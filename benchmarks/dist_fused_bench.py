@@ -64,7 +64,8 @@ def main() -> None:
     args = ap.parse_args()
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = jax.make_mesh((n_dev,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     reps = 10 if args.quick else 30
     # enc: 2-D, columns = last axis; blocks: stacked with axis=1 (transpose
     # entries — where the unfused pack pays physical transposes per step)

@@ -48,7 +48,8 @@ def main() -> None:
     args = ap.parse_args()
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = jax.make_mesh((n_dev,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     reps = 10 if args.quick else 30
     k_mats, n, m = (4, 256, 1024) if args.quick else (6, 512, 4096)
 

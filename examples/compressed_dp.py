@@ -17,7 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.dist.compression import compressed_psum
 
@@ -45,7 +45,7 @@ def dp_step(mode):
         return g
 
     fn = shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     with mesh:
         return jax.jit(fn)(X, Y)
 

@@ -50,9 +50,9 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 if [[ "${1:-}" == "--bench-smoke" ]]; then
     echo "== bench smoke: projection engine (local + sharded) =="
-    # benchmarks.run swallows per-bench failures (prints an ERROR row,
-    # exits 0); removing the artifacts first guarantees the gate below
-    # reads THIS run's numbers or fails loudly — never stale files
+    # benchmarks.run exits non-zero when a phase raises; removing the
+    # artifacts first also guarantees the gate below reads THIS run's
+    # numbers or fails loudly — never stale files
     rm -f BENCH_proj.json BENCH_families.json BENCH_dist_proj.json \
           BENCH_fused_step.json BENCH_serve.json BENCH_zoo_serve.json \
           BENCH_fleet_serve.json BENCH_dist_fused.json

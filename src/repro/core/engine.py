@@ -9,7 +9,8 @@ state. This module centralizes the runtime side of the constraint system:
   * ``ProjectionEngine`` owns plan building (``core.constraints``), packing,
     per-plan theta state, and solver dispatch:
       - ``newton``  — single-buffer segmented Newton (default, 1 device);
-      - ``pallas``  — fused-kernel engine (interpret mode off-TPU);
+      - ``pallas``  — fused-kernel engine (compiled on TPU, interpreted
+        elsewhere: ``kernels.backend.resolve_impl``);
       - ``sharded`` — mesh-resident shard_map solve (``dist.projection``):
         weight shards never gather; per-segment statistics cross the link
         as one (num_segments,) psum per Newton evaluation.
@@ -127,8 +128,7 @@ class ProjectionEngine:
             pallas_fn = fam.pallas_loader()
             Xpk, theta = pallas_fn(
                 Ypk, sids, C_seg, num_segments=plan.num_segments,
-                theta0=theta0,
-                interpret=jax.default_backend() != "tpu")
+                theta0=theta0)
             iters = jnp.asarray(-1, jnp.int32)   # kernel keeps its own count
         else:
             Xpk, theta, iters = project_segmented_family(

@@ -17,7 +17,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["build_pipeline_fn"]
 
@@ -68,4 +68,4 @@ def build_pipeline_fn(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         return jax.lax.psum(out, axis_name)
 
     return shard_map(body, mesh=mesh, in_specs=(P(axis_name), P()),
-                     out_specs=P(), check_rep=False)
+                     out_specs=P(), check_vma=False)

@@ -42,7 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.constraints import (PackedPlan, _PackedEntry, _pack_entry,
                                 _unpack_entry, _LANE)
@@ -212,7 +212,7 @@ def project_plan_sharded(leaves: Sequence[jnp.ndarray], plan: PackedPlan,
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(None),) + leaf_specs,
                    out_specs=(leaf_specs, P(None), P()),
-                   check_rep=False)
+                   check_vma=False)
     outs, theta, iters = fn(jnp.asarray(theta0, jnp.float32), *leaves)
     return list(outs), theta, iters
 
@@ -352,7 +352,7 @@ def fused_plan_sharded(plan: PackedPlan, mesh: Mesh,
                              leaf_specs, leaf_specs, leaf_specs),
                    out_specs=(leaf_specs, leaf_specs, leaf_specs,
                               P(None), P()),
-                   check_rep=False)
+                   check_vma=False)
     new_p, new_m, new_v, theta, iters = fn(
         jnp.asarray(theta0, jnp.float32), sc, tuple(g_leaves),
         tuple(m_leaves), tuple(v_leaves), tuple(p_leaves),

@@ -81,18 +81,18 @@ def _adam_colstats_kernel(sc_ref, g_ref, m_ref, v_ref, p_ref, *rest,
                 with_moment_update=True)
     a = jnp.abs(u.astype(jnp.float32))
     red = 1 if transpose else 0
-    psum = jnp.sum(a * a if stat == "sq" else a, axis=red)[None, :]
-    pmax = jnp.max(a, axis=red)[None, :]
+    psum = jnp.sum(a * a if stat == "sq" else a, axis=red, keepdims=True)
+    pmax = jnp.max(a, axis=red, keepdims=True)
 
     @pl.when(i == 0)
     def _init():
-        sum_ref[...] = psum
-        max_ref[...] = pmax
+        sum_ref[0] = psum
+        max_ref[0] = pmax
 
     @pl.when(i > 0)
     def _acc():
-        sum_ref[...] = sum_ref[...] + psum
-        max_ref[...] = jnp.maximum(max_ref[...], pmax)
+        sum_ref[0] = sum_ref[0] + psum
+        max_ref[0] = jnp.maximum(max_ref[0], pmax)
 
 
 def _adam_clip_apply_kernel(sc_ref, m_ref, v_ref, p_ref, mu_ref, *rest,
@@ -107,8 +107,7 @@ def _adam_clip_apply_kernel(sc_ref, m_ref, v_ref, p_ref, mu_ref, *rest,
                 None, None, b1=b1, b2=b2, eps=eps, wd=wd,
                 with_moment_update=False)
     uf = u.astype(jnp.float32)
-    mu = mu_ref[0]                                    # (bm,)
-    mu_b = mu[:, None] if transpose else mu[None, :]
+    mu_b = mu_ref[0]                        # (bm, 1) if transpose else (1, bm)
     if mode == "scale":
         x = uf * mu_b
     else:
@@ -148,7 +147,18 @@ def _data_spec(bm, bn, transpose):
     return pl.BlockSpec((1, bn, bm), lambda l, j, i, sc: (l, i, j))
 
 
-_STAT_SPEC = lambda bm: pl.BlockSpec((1, bm), lambda l, j, i, sc: (l, j))
+def _stat_spec(bm, transpose):
+    """Per-column statistics and clip levels ride as (L, 1, m) — or
+    (L, m, 1) when the columns are the sublane dim — so that a block's last
+    two dims are (1, bm) / (bm, 1): Mosaic tiles those for any lead count
+    L, where an (L, m) array with (1, bm) blocks compiles only for L = 1."""
+    if transpose:
+        return pl.BlockSpec((1, bm, 1), lambda l, j, i, sc: (l, j, 0))
+    return pl.BlockSpec((1, 1, bm), lambda l, j, i, sc: (l, 0, j))
+
+
+def _stat_shape(L, mcols, transpose):
+    return (L, mcols, 1) if transpose else (L, 1, mcols)
 
 
 def adam_colstats(sc, g, m, v, p, mask=None, *, moment_dtype,
@@ -157,8 +167,9 @@ def adam_colstats(sc, g, m, v, p, mask=None, *, moment_dtype,
     """Pass-1 launch on padded (L, Rp, Cp) views (see module docstring).
 
     ``sc``: (4,) f32 traced scalars [clip_scale, lr_t, b1c, b2c]. Returns
-    (m_new, v_new (L, Rp, Cp) in ``moment_dtype``, colsum, colmax (L, mcols)
-    f32). ``stat``: "abs" accumulates sum |u| into colsum, "sq" sum u^2
+    (m_new, v_new (L, Rp, Cp) in ``moment_dtype``, colsum, colmax f32 in
+    the ``_stat_shape`` layout: (L, mcols, 1) if ``transpose`` else
+    (L, 1, mcols)). ``stat``: "abs" accumulates sum |u| into colsum, "sq" sum u^2
     (l1,2 column energies). Rp must be a multiple of 16 and Cp of 128
     (``ops.py`` pads).
     """
@@ -179,15 +190,18 @@ def adam_colstats(sc, g, m, v, p, mask=None, *, moment_dtype,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[data(), data(), _STAT_SPEC(bm), _STAT_SPEC(bm)],
+        out_specs=[data(), data(), _stat_spec(bm, transpose),
+                   _stat_spec(bm, transpose)],
     )
     m_new, v_new, colsum, colmax = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((L, Rp, Cp), moment_dtype),
                    jax.ShapeDtypeStruct((L, Rp, Cp), moment_dtype),
-                   jax.ShapeDtypeStruct((L, mcols), jnp.float32),
-                   jax.ShapeDtypeStruct((L, mcols), jnp.float32)],
+                   jax.ShapeDtypeStruct(_stat_shape(L, mcols, transpose),
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct(_stat_shape(L, mcols, transpose),
+                                        jnp.float32)],
         interpret=interpret,
     )(sc, *args)
     return m_new, v_new, colsum, colmax
@@ -198,7 +212,8 @@ def adam_clip_apply(sc, m, v, p, mu, mask=None, *,
                     interpret: bool = False):
     """Pass-2 launch: clipped params (L, Rp, Cp) in p's dtype.
 
-    ``mu``: (L, mcols) f32 per-column clip level (sentinel-folded by the
+    ``mu``: f32 per-column clip level in the ``_stat_shape`` layout
+    (sentinel-folded by the
     engine: 1e30 = identity, 0 = dead column). ``mode``: "clip" writes
     sign(u) * min(|u|, mu), "scale" writes u * mu (per-column multiplier,
     identity sentinel 1.0). Same padding contract as ``adam_colstats``.
@@ -210,7 +225,7 @@ def adam_clip_apply(sc, m, v, p, mu, mask=None, *,
                              wd=wd, has_mask=mask is not None,
                              transpose=transpose, mode=mode)
     data = lambda: _data_spec(bm, bn, transpose)
-    in_specs = [data(), data(), data(), _STAT_SPEC(bm)]
+    in_specs = [data(), data(), data(), _stat_spec(bm, transpose)]
     args = [m, v, p, mu]
     if mask is not None:
         in_specs.append(data())

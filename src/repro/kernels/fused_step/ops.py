@@ -13,8 +13,9 @@ copy, the caller only threads per-leaf slices of the flat statistics
 vector. ``impl`` picks the backend: ``"pallas"`` (the TPU kernels of
 ``kernel.py``; interpret mode off-TPU), ``"ref"`` (the jnp twins of
 ``ref.py`` — what XLA fuses best on CPU/GPU), or ``"auto"`` (pallas on
-TPU, ref elsewhere). The two implementations are tile-for-tile identical;
-tests diff them in interpret mode.
+TPU, ref elsewhere); ``kernels.backend.resolve_impl`` makes that choice.
+The two implementations are tile-for-tile identical; tests diff them in
+interpret mode.
 
 The step scalars (lr_t, b1c, b2c) come from ``optim.adam.adam_scalars``
 and ``scale`` from ``optim.adam.clip_scale`` so the fused and unfused
@@ -22,24 +23,16 @@ paths share one definition of the update math.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from . import ref
 from . import kernel as _k
+from ..backend import resolve_impl
 
 __all__ = ["fused_adam_colstats", "fused_adam_clip_apply"]
 
 _SUB = 16     # sublane padding multiple (bf16-safe; f32 needs only 8)
 _LANE = 128   # lane padding multiple
-
-
-def _resolve(impl: str) -> str:
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl not in ("pallas", "ref"):
-        raise ValueError(f"unknown impl {impl!r} (auto | pallas | ref)")
-    return impl
 
 
 def _view3(x):
@@ -92,7 +85,8 @@ def fused_adam_colstats(g, m, v, p, *, cfg, lr_t, b1c, b2c,
     """
     if stat not in ("abs", "sq"):
         raise ValueError(f"unknown stat {stat!r} (abs | sq)")
-    if _resolve(impl) == "ref":
+    impl, interpret = resolve_impl(impl, interpret)
+    if impl == "ref":
         return ref.adam_colstats_ref(g, m, v, p, cfg=cfg, lr_t=lr_t,
                                      b1c=b1c, b2c=b2c, scale=scale,
                                      mask=mask, transpose=transpose,
@@ -106,12 +100,12 @@ def fused_adam_colstats(g, m, v, p, *, cfg, lr_t, b1c, b2c,
         _scalars(scale, lr_t, b1c, b2c), pad(g), pad(m), pad(v), pad(p), mk,
         moment_dtype=cfg.moment_dtype, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps,
         wd=cfg.weight_decay, transpose=transpose, stat=stat,
-        interpret=(jax.default_backend() != "tpu"
-                   if interpret is None else interpret))
+        interpret=interpret)
     mcols = R if transpose else C
+    flat = lambda s: s.reshape(s.shape[0], -1)[:, :mcols]
     return (m_new[:, :R, :C].reshape(shape),
             v_new[:, :R, :C].reshape(shape),
-            colsum[:, :mcols], colmax[:, :mcols])
+            flat(colsum), flat(colmax))
 
 
 def fused_adam_clip_apply(m, v, p, mu, *, cfg, lr_t, b1c, b2c,
@@ -136,7 +130,8 @@ def fused_adam_clip_apply(m, v, p, mu, *, cfg, lr_t, b1c, b2c,
     """
     if mode not in ("clip", "scale"):
         raise ValueError(f"unknown mode {mode!r} (clip | scale)")
-    if _resolve(impl) == "ref":
+    impl, interpret = resolve_impl(impl, interpret)
+    if impl == "ref":
         return ref.adam_clip_apply_ref(m, v, p, mu, cfg=cfg, lr_t=lr_t,
                                        b1c=b1c, b2c=b2c, mask=mask,
                                        transpose=transpose, mode=mode)
@@ -146,13 +141,12 @@ def fused_adam_clip_apply(m, v, p, mu, *, cfg, lr_t, b1c, b2c,
     pad = lambda x: _pad3(_view3(x), Rp, Cp)
     mk = None if mask is None else pad(mask)
     mcols_p = Rp if transpose else Cp
-    mu3 = jnp.asarray(mu, jnp.float32)
-    if mu3.shape[1] != mcols_p:
-        mu3 = jnp.pad(mu3, ((0, 0), (0, mcols_p - mu3.shape[1])))
+    mu2 = jnp.asarray(mu, jnp.float32)
+    if mu2.shape[1] != mcols_p:
+        mu2 = jnp.pad(mu2, ((0, 0), (0, mcols_p - mu2.shape[1])))
+    mu3 = mu2.reshape(_k._stat_shape(mu2.shape[0], mcols_p, transpose))
     x = _k.adam_clip_apply(
         _scalars(None, lr_t, b1c, b2c), pad(m), pad(v), pad(p), mu3, mk,
         b1=cfg.b1, b2=cfg.b2, eps=cfg.eps, wd=cfg.weight_decay,
-        transpose=transpose, mode=mode,
-        interpret=(jax.default_backend() != "tpu"
-                   if interpret is None else interpret))
+        transpose=transpose, mode=mode, interpret=interpret)
     return x[:, :R, :C].reshape(shape)

@@ -28,8 +28,10 @@ through the loop carry) makes the J-proportional claim measurable in
 interpret mode; ``return_stats=True`` exposes it together with the Newton
 evaluation count.
 
-On non-TPU backends the kernels run in interpret mode (correctness
-validation); the lowering target is TPU.
+Column blocks are always one 128-lane tile; fast memory is bounded by
+tiling rows (``_padded``). ``interpret`` is resolved by
+``kernels.backend.resolve_impl``: compiled on TPU, the interpreter
+elsewhere, unless the caller says otherwise.
 """
 from __future__ import annotations
 
@@ -39,25 +41,34 @@ import jax
 import jax.numpy as jnp
 
 from ...core.l1inf import _PAD_THETA, active_compaction
+from ..backend import resolve_impl
 from .kernel import colstats, mu_solve, clip_apply
 
-
-def _pad_to(x: jnp.ndarray, mult0: int, mult1: int) -> jnp.ndarray:
-    n, m = x.shape
-    pn = (-n) % mult0
-    pm = (-m) % mult1
-    if pn or pm:
-        x = jnp.pad(x, ((0, pn), (0, pm)))
-    return x
+_LANE = 128          # column block: one full lane tile, whatever n is
+_CHUNK_ROWS = 4096   # mu_solve's VMEM row chunk: 4096 x 128 f32 = 2 MiB
 
 
-def _pick_block_m(n_pad: int, vmem_budget: int = 4 * 1024 * 1024) -> int:
-    """Largest power-of-two block_m <= 128 such that an (n_pad, bm) f32 tile
-    fits the VMEM budget (TPU lane dim prefers 128)."""
-    bm = 128
-    while bm > 8 and n_pad * bm * 4 > vmem_budget:
-        bm //= 2
-    return bm
+def _padded(Y: jnp.ndarray):
+    """Zero-pad Y to (n_pad, m_pad) and pick ``mu_solve``'s row chunk.
+
+    Columns pad to the 128-lane block. Rows pad to 8; a column taller than
+    ``_CHUNK_ROWS`` pads to a multiple of 512 instead and is cut into the
+    largest chunk of 512-row pieces that divides it, so the other kernels'
+    row tiles stay 512. Zero rows and columns change no column statistic
+    and no water level. Returns (Ypad, chunk rows).
+    """
+    n, m = Y.shape
+    n_pad = -(-n // 8) * 8
+    chunk = n_pad
+    if n_pad > _CHUNK_ROWS:
+        n_pad = -(-n // 512) * 512
+        k = n_pad // 512
+        chunk = 512 * max(d for d in range(1, _CHUNK_ROWS // 512 + 1)
+                          if k % d == 0)
+    m_pad = -(-m // _LANE) * _LANE
+    if (n_pad, m_pad) != (n, m):
+        Y = jnp.pad(Y, ((0, n_pad - n), (0, m_pad - m)))
+    return Y, chunk
 
 
 def _pick_block_n(n_pad: int, cap: int = 512) -> int:
@@ -77,7 +88,7 @@ def _pick_block_n(n_pad: int, cap: int = 512) -> int:
     return best
 
 
-def _engine(Ypad, seg_ids, C_seg, num_segments, theta0, *, bm, n_bisect,
+def _engine(Ypad, seg_ids, C_seg, num_segments, theta0, *, chunk, n_bisect,
             n_polish, max_newton, interpret, shrink):
     """Shared sparsity-adaptive Newton engine over a padded (n_pad, m_pad)
     buffer whose columns map to `num_segments` independent balls (plus the
@@ -95,6 +106,7 @@ def _engine(Ypad, seg_ids, C_seg, num_segments, theta0, *, bm, n_bisect,
     """
     n_pad, m_pad = Ypad.shape
     G = int(num_segments)
+    bm = _LANE
     nblocks = m_pad // bm
     Aabs = jnp.abs(Ypad.astype(jnp.float32))
     seg_ids = jnp.asarray(seg_ids, jnp.int32)
@@ -126,6 +138,7 @@ def _engine(Ypad, seg_ids, C_seg, num_segments, theta0, *, bm, n_bisect,
     def eval_step(th_seg, A, sids, nact_blocks):
         """One mu_solve launch + segmented Eq.-(19) update at th_seg."""
         mu, k, S, act = mu_solve(A, theta_cols(th_seg, sids), block_m=bm,
+                                 block_rows=chunk,
                                  n_bisect=n_bisect, n_polish=n_polish,
                                  interpret=interpret,
                                  nact_blocks=nact_blocks)
@@ -213,45 +226,42 @@ def _engine(Ypad, seg_ids, C_seg, num_segments, theta0, *, bm, n_bisect,
     return mu_full, theta, norm_seg, colsum, stats
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "n_bisect",
-                                             "n_polish", "max_newton",
-                                             "interpret", "shrink",
-                                             "return_stats"))
-def project_l1inf_pallas(Y: jnp.ndarray, C, *, theta0=None, block_m: int = 0,
+@functools.partial(jax.jit, static_argnames=("n_bisect", "n_polish",
+                                             "max_newton", "interpret",
+                                             "shrink", "return_stats"))
+def project_l1inf_pallas(Y: jnp.ndarray, C, *, theta0=None,
                          n_bisect: int = 26, n_polish: int = 8,
-                         max_newton: int = 32, interpret: bool = True,
+                         max_newton: int = 32, interpret=None,
                          shrink: bool = True, return_stats: bool = False):
     """Exact projection of Y (n, m; max over axis 0) onto the l1,inf ball.
 
     Sort-free sparsity-adaptive engine: outer monotone Newton on theta
     (Eq. 19, warm-startable via ``theta0``), inner fused VMEM bisection +
     polish per column, active-column shrinking after the first pass.
-    ``interpret=True`` for CPU validation. With ``return_stats=True``
-    returns (X, stats) where stats carries the Newton-evaluation count and
-    the ``work_cols`` counter (columns swept across all mu_solve launches).
+    ``interpret``: see ``kernels.backend.resolve_impl``. With
+    ``return_stats=True`` returns (X, stats) where stats carries the
+    Newton-evaluation count and the ``work_cols`` counter (columns swept
+    across all mu_solve launches).
     """
     if Y.ndim != 2:
         raise ValueError("expected 2-D input")
     n, m = Y.shape
     C = jnp.asarray(C, jnp.float32)
+    interpret = resolve_impl("pallas", interpret)[1]
 
-    Ypad = _pad_to(Y, 8, 128)
+    Ypad, chunk = _padded(Y)
     n_pad, m_pad = Ypad.shape
-    bm = block_m or _pick_block_m(n_pad)
-    if m_pad % bm:
-        Ypad = _pad_to(Ypad, 8, bm)
-        n_pad, m_pad = Ypad.shape
     seg_ids = jnp.where(jnp.arange(m_pad) < m, 0, 1).astype(jnp.int32)
     th0 = None if theta0 is None else jnp.reshape(
         jnp.asarray(theta0, jnp.float32), (1,))
 
     mu_full, theta, norm_seg, colsum, stats = _engine(
-        Ypad, seg_ids, jnp.reshape(C, (1,)), 1, th0, bm=bm,
+        Ypad, seg_ids, jnp.reshape(C, (1,)), 1, th0, chunk=chunk,
         n_bisect=n_bisect, n_polish=n_polish, max_newton=max_newton,
         interpret=interpret, shrink=shrink)
 
     bn = _pick_block_n(n_pad)
-    Xpad = clip_apply(Ypad, mu_full.astype(Ypad.dtype), block_m=bm,
+    Xpad = clip_apply(Ypad, mu_full.astype(Ypad.dtype), block_m=_LANE,
                       block_n=bn, interpret=interpret)
     X = Xpad[:n, :m]
     inside = norm_seg[0] <= C
@@ -266,15 +276,15 @@ def project_l1inf_pallas(Y: jnp.ndarray, C, *, theta0=None, block_m: int = 0,
     return X, stats
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments", "block_m",
-                                             "n_bisect", "n_polish",
-                                             "max_newton", "interpret",
-                                             "shrink", "return_stats"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "n_bisect",
+                                             "n_polish", "max_newton",
+                                             "interpret", "shrink",
+                                             "return_stats"))
 def project_l1inf_pallas_segmented(Y: jnp.ndarray, seg_ids: jnp.ndarray,
                                    C_seg, *, num_segments: int, theta0=None,
-                                   block_m: int = 0, n_bisect: int = 26,
+                                   n_bisect: int = 26,
                                    n_polish: int = 8, max_newton: int = 32,
-                                   interpret: bool = True,
+                                   interpret=None,
                                    shrink: bool = True,
                                    return_stats: bool = False):
     """Packed multi-ball projection: one engine run, one kernel launch per
@@ -291,24 +301,21 @@ def project_l1inf_pallas_segmented(Y: jnp.ndarray, seg_ids: jnp.ndarray,
     n, m = Y.shape
     G = int(num_segments)
     C_seg = jnp.asarray(C_seg, jnp.float32)
+    interpret = resolve_impl("pallas", interpret)[1]
 
-    Ypad = _pad_to(Y, 8, 128)
+    Ypad, chunk = _padded(Y)
     n_pad, m_pad = Ypad.shape
-    bm = block_m or _pick_block_m(n_pad)
-    if m_pad % bm:
-        Ypad = _pad_to(Ypad, 8, bm)
-        n_pad, m_pad = Ypad.shape
     sids = jnp.full((m_pad,), G, jnp.int32).at[:m].set(
         jnp.asarray(seg_ids, jnp.int32))
     th0 = None if theta0 is None else jnp.asarray(theta0, jnp.float32)
 
     mu_full, theta, norm_seg, colsum, stats = _engine(
-        Ypad, sids, C_seg, G, th0, bm=bm, n_bisect=n_bisect,
+        Ypad, sids, C_seg, G, th0, chunk=chunk, n_bisect=n_bisect,
         n_polish=n_polish, max_newton=max_newton, interpret=interpret,
         shrink=shrink)
 
     bn = _pick_block_n(n_pad)
-    Xpad = clip_apply(Ypad, mu_full.astype(Ypad.dtype), block_m=bm,
+    Xpad = clip_apply(Ypad, mu_full.astype(Ypad.dtype), block_m=_LANE,
                       block_n=bn, interpret=interpret)
 
     inside_seg = norm_seg <= C_seg
@@ -330,13 +337,12 @@ def project_l1inf_pallas_segmented(Y: jnp.ndarray, seg_ids: jnp.ndarray,
     return X, theta_out, stats
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments", "block_m",
-                                             "max_newton", "interpret",
-                                             "return_stats"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "max_newton",
+                                             "interpret", "return_stats"))
 def project_bilevel_pallas_segmented(Y: jnp.ndarray, seg_ids: jnp.ndarray,
                                      C_seg, *, num_segments: int, theta0=None,
-                                     block_m: int = 0, max_newton: int = 32,
-                                     interpret: bool = True,
+                                     max_newton: int = 32,
+                                     interpret=None,
                                      return_stats: bool = False):
     """Packed multi-ball BI-LEVEL projection (arXiv:2407.16293) on the fused
     kernels: same contract as ``project_l1inf_pallas_segmented``.
@@ -361,18 +367,15 @@ def project_bilevel_pallas_segmented(Y: jnp.ndarray, seg_ids: jnp.ndarray,
     n, m = Y.shape
     G = int(num_segments)
     C_seg = jnp.asarray(C_seg, jnp.float32)
+    interpret = resolve_impl("pallas", interpret)[1]
 
-    Ypad = _pad_to(Y, 8, 128)
+    Ypad, _ = _padded(Y)
     n_pad, m_pad = Ypad.shape
-    bm = block_m or _pick_block_m(n_pad)
-    if m_pad % bm:
-        Ypad = _pad_to(Ypad, 8, bm)
-        n_pad, m_pad = Ypad.shape
     sids = jnp.full((m_pad,), G, jnp.int32).at[:m].set(
         jnp.asarray(seg_ids, jnp.int32))
     valid = sids < G
     bn = _pick_block_n(n_pad)
-    _, u = colstats(jnp.abs(Ypad.astype(jnp.float32)), block_m=bm,
+    _, u = colstats(jnp.abs(Ypad.astype(jnp.float32)), block_m=_LANE,
                     block_n=bn, interpret=interpret)
 
     sum_seg = functools.partial(jax.ops.segment_sum, segment_ids=sids,
@@ -423,8 +426,8 @@ def project_bilevel_pallas_segmented(Y: jnp.ndarray, seg_ids: jnp.ndarray,
                       lambda: eval_step(theta)[1],
                       lambda: mu)
 
-    Xpad = clip_apply(Ypad, mu.astype(Ypad.dtype), block_m=bm, block_n=bn,
-                      interpret=interpret)
+    Xpad = clip_apply(Ypad, mu.astype(Ypad.dtype), block_m=_LANE,
+                      block_n=bn, interpret=interpret)
     inside_seg = norm_seg <= C_seg
     zero_seg = C_seg <= 0
     ext_in = jnp.concatenate([inside_seg, jnp.array([True])])

@@ -1,8 +1,11 @@
 """Training launcher.
 
-CPU-scale real runs (examples, CI) and production-mesh launches share this
-entry point; on a real cluster each host runs the same command and jax
-initializes the distributed runtime from the environment.
+CPU-scale real runs (examples, CI) and chip launches share this entry
+point. The mesh spans every visible device as a data axis, so the params,
+optimizer state and batches are laid out over all attached chips and the
+projection takes the engine ``launch.steps.projection_engine_for`` picks
+for that mesh. ``main(argv)`` returns the training result, so a caller can
+drive it in its own process.
 
     python -m repro.launch.train --arch gemma_7b --reduced --steps 200
     python -m repro.launch.train --arch mamba2_370m --reduced --resume auto
@@ -12,16 +15,17 @@ from __future__ import annotations
 import argparse
 
 import jax
-import jax.numpy as jnp
 
 from ..configs import ARCH_IDS, get_config, get_reduced
+from ..dist.sharding import default_rules
 from ..models.zoo import build
 from ..data.pipeline import SyntheticLM, LMBatcher
 from ..train.loop import TrainConfig, train
+from .cache import enable_compile_cache
 from .mesh import make_local_mesh
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true",
@@ -35,7 +39,8 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", default="auto", choices=["auto", "none"])
     ap.add_argument("--no-projection", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build(cfg)
@@ -47,7 +52,9 @@ def main():
                        microbatches=args.microbatches,
                        ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                        with_projection=not args.no_projection)
-    out = train(model, batcher, tcfg, resume=(args.resume == "auto"))
+    mesh = make_local_mesh(data=len(jax.devices()))
+    out = train(model, batcher, tcfg, mesh=mesh, rules=default_rules(),
+                resume=(args.resume == "auto"))
     print(f"[launch] final loss {out['losses'][-1]:.4f}; "
           f"first loss {out['losses'][0]:.4f}")
     wd = out["watchdog"]
@@ -59,6 +66,7 @@ def main():
     if out["sparsity"]:
         for k, v in out["sparsity"].items():
             print(f"[sparsity] {k}: {v:.1f}% columns zero")
+    return out
 
 
 if __name__ == "__main__":

@@ -28,7 +28,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..dist.sharding import current_rules
 
@@ -130,7 +130,7 @@ def moe_apply_shardmap(params, x: jnp.ndarray, *, n_experts: int, top_k: int,
                   P(batch_axes, None, None)),
         out_specs=(P(batch_axes, None, None),
                    {"lb_loss": P(), "z_loss": P(), "dropped_frac": P()}),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(params["router"], params["w1"], params["w3"], params["w2"],
                 x)
     if "shared" in params:

@@ -100,11 +100,14 @@ def ssd_apply(params, u: jnp.ndarray, *, headdim: int, chunk: int = 64,
     seg_total = cum[:, :, -1]                                      # (B,nc,H)
 
     # ---- intra-chunk (quadratic within chunk) ------------------------------
-    # L[b,c,h,i,j] = exp(cum_i - cum_j) for i >= j else 0
+    # L[b,c,h,i,j] = exp(cum_i - cum_j) for i >= j else 0. Mask BEFORE the
+    # exp: above the diagonal cum_i - cum_j > 0 grows with the chunk and
+    # overflows exp to inf, and the masked inf turns into NaN gradients
     tdt = jnp.bfloat16 if tile_bf16 else jnp.float32
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]           # (B,nc,Q,Q,H)
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(tri[None, None, :, :, None], jnp.exp(diff), 0.0).astype(tdt)
+    L = jnp.exp(jnp.where(tri[None, None, :, :, None], diff,
+                          -jnp.inf)).astype(tdt)
     G = jnp.einsum("bcin,bcjn->bcij", C_c.astype(tdt),
                    B_c.astype(tdt))                                # (B,nc,Q,Q)
     M = G[..., None] * L                                           # (B,nc,Q,Q,H)

@@ -172,7 +172,7 @@ def make_serve_step(compact: CompactSAE, *, mesh=None, rules=None):
         return jax.jit(_apply)
 
     from ..dist.sharding import default_rules
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     rules = default_rules() if rules is None else rules
     batch_axes = rules.get("batch")
     if batch_axes is None:
@@ -183,5 +183,5 @@ def make_serve_step(compact: CompactSAE, *, mesh=None, rules=None):
     fn = shard_map(_apply, mesh=mesh,
                    in_specs=(P(), P(batch_axes, None)),
                    out_specs=P(batch_axes, None),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)

@@ -501,7 +501,7 @@ class FleetEngine:
         if self._mesh is None:
             return jax.jit(self._traced_step, donate_argnums=(1, 2))
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from ..dist.sharding import default_rules
         rules = dict(default_rules() if self._rules is None else self._rules)
         batch_axes = rules.get("batch")
@@ -518,7 +518,7 @@ class FleetEngine:
         fn = shard_map(self._traced_step, mesh=self._mesh,
                        in_specs=(P(), cspecs, sspecs, aspecs),
                        out_specs=(ospecs, cspecs, sspecs),
-                       check_rep=False)
+                       check_vma=False)
         return jax.jit(fn, donate_argnums=(1, 2))
 
     def _ensure_ready(self):

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.zoo import Model
 from ..optim import AdamConfig, adam_init
@@ -23,6 +24,8 @@ from ..checkpoint import AsyncCheckpointer, latest_step, restore_tree
 from ..dist.sharding import axis_rules
 from ..dist.watchdog import StepWatchdog
 from ..data.pipeline import LMBatcher
+from ..launch.steps import (batch_shardings, opt_shardings, param_shardings,
+                            projection_engine_for)
 
 
 @dataclasses.dataclass
@@ -36,9 +39,6 @@ class TrainConfig:
     lr: float = 3e-4
     warmup: int = 20
     with_projection: bool = True
-    proj_solver: str = "fused"     # engine solver; "fused" = two-HBM-pass
-                                   # step where the family supports it,
-                                   # bit-equal Newton fallback elsewhere
     seed: int = 0
 
 
@@ -46,12 +46,10 @@ def build_accum_step(model: Model, acfg: AdamConfig, tcfg: TrainConfig,
                      mesh=None, rules=None, engine: ProjectionEngine = None):
     """jit'd train step with optional microbatch accumulation via lax.scan.
     The update half is the shared ``ProjectionEngine.projected_update`` step
-    core (Adam + packed warm-started projection + every_k gate)."""
-    cfg = model.cfg
+    core (Adam + packed warm-started projection + every_k gate); the
+    default engine is ``launch.steps.projection_engine_for`` this mesh."""
     if engine is None:
-        engine = ProjectionEngine(
-            cfg.projection_specs if tcfg.with_projection else (),
-            solver=tcfg.proj_solver)
+        engine = projection_engine_for(model.cfg, mesh, tcfg.with_projection)
 
     def loss_fn(params, batch):
         return model.loss(params, batch)
@@ -95,15 +93,18 @@ def train(model: Model, batcher: LMBatcher, tcfg: TrainConfig,
           mesh=None, rules=None, resume: bool = True,
           on_step: Optional[Callable[[int, float, float], None]] = None
           ) -> Dict[str, Any]:
-    """Run the loop; auto-resumes from the latest checkpoint if present."""
+    """Run the loop; auto-resumes from the latest checkpoint if present.
+
+    With a ``mesh`` (and its ``rules``) the params, optimizer state and
+    batches are laid out on it by ``launch.steps``' shardings and the
+    projection runs on the engine ``projection_engine_for`` picks for it;
+    without one everything runs on the default device."""
     acfg = AdamConfig(lr=tcfg.lr)
     params = model.init(jax.random.PRNGKey(tcfg.seed))
     opt_state = adam_init(params, acfg)
     start_step = 0
 
-    engine = ProjectionEngine(
-        model.cfg.projection_specs if tcfg.with_projection else (),
-        solver=tcfg.proj_solver)
+    engine = projection_engine_for(model.cfg, mesh, tcfg.with_projection)
     proj_state = engine.init_state(params)
 
     ckpt = None
@@ -126,6 +127,15 @@ def train(model: Model, batcher: LMBatcher, tcfg: TrainConfig,
             params, opt_state = state["params"], state["opt"]
             print(f"[train] resumed from step {start_step}")
 
+    place_batch = lambda b: b
+    if mesh is not None:
+        p_sh = param_shardings(model, mesh, rules)
+        params = jax.device_put(params, p_sh)
+        opt_state = jax.device_put(opt_state, opt_shardings(p_sh, mesh))
+        proj_state = jax.device_put(proj_state, NamedSharding(mesh, P()))
+        place_batch = lambda b: jax.device_put(
+            b, batch_shardings(b, mesh, rules))
+
     step_fn = build_accum_step(model, acfg, tcfg, mesh, rules, engine=engine)
     watchdog = StepWatchdog(on_straggler=lambda s, dt, ew: print(
         f"[watchdog] straggler step {s}: {dt:.3f}s vs EWMA {ew:.3f}s"))
@@ -133,7 +143,8 @@ def train(model: Model, batcher: LMBatcher, tcfg: TrainConfig,
     losses = []
     step_metrics = []   # per-step watchdog snapshots (dist/watchdog.py)
     for step in range(start_step, tcfg.steps):
-        batch = jax.tree_util.tree_map(jnp.asarray, batcher.get(step))
+        batch = place_batch(
+            jax.tree_util.tree_map(jnp.asarray, batcher.get(step)))
         watchdog.start()
         params, opt_state, proj_state, loss = step_fn(
             params, opt_state, proj_state, batch, lr_at(tcfg, step))

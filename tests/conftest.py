@@ -1,11 +1,6 @@
-"""Test-suite bootstrap.
-
-* Puts src/ on sys.path so `python -m pytest` works without PYTHONPATH
-  (pyproject's pythonpath ini handles pytest>=7; this covers direct runs).
-* Falls back to the deterministic hypothesis stub (tests/_hypothesis_stub.py)
-  when the real hypothesis package is not installed, so the property-test
-  modules collect and run everywhere (the CI container has no network).
-"""
+"""Test-suite bootstrap: puts src/ on sys.path so `python -m pytest` works
+without PYTHONPATH (pyproject's pythonpath ini handles pytest>=7; this
+covers direct runs)."""
 import os
 import sys
 
@@ -13,10 +8,3 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_ROOT, "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_stub
-    _hypothesis_stub.install(sys.modules)

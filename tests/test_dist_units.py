@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.dist.compression import (compressed_psum, ef_step, int8_dequantize,
                                     int8_quantize, topk_compress,
@@ -19,6 +19,12 @@ from repro.dist.pipeline import build_pipeline_fn
 from repro.dist.sharding import (axis_rules, current_rules, default_rules,
                                  logical_spec, shard)
 from repro.dist.watchdog import StepWatchdog
+
+
+def _auto_mesh(axis):
+    # jax.make_mesh defaults to Explicit axes, which with_sharding_constraint
+    # refuses; the repo's meshes (launch/mesh.py) are Auto
+    return jax.make_mesh((1,), (axis,), axis_types=(AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------
@@ -220,7 +226,7 @@ def test_logical_spec_and_context():
     assert logical_spec(("batch", "vocab"), rules) == P("data", "model")
     assert logical_spec(("nope", None), rules) == P(None, None)
     assert current_rules() is None
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = _auto_mesh("data")
     with axis_rules(mesh, rules):
         assert current_rules() == (mesh, rules)
         with axis_rules(None, None):  # nesting: innermost wins
@@ -237,7 +243,7 @@ def test_shard_noop_outside_context_and_on_none_mesh():
 
 
 def test_shard_constrains_and_drops_nondivisible():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = _auto_mesh("data")
     rules = {"batch": "data", "ghost": "absent_axis"}
     x = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
     with axis_rules(mesh, rules):
@@ -253,7 +259,7 @@ def test_shard_constrains_and_drops_nondivisible():
 # ---------------------------------------------------------------------------
 
 def test_pipeline_single_stage_identity_schedule():
-    mesh = jax.make_mesh((1,), ("pp",))
+    mesh = _auto_mesh("pp")
     rng = np.random.default_rng(3)
     W = jnp.asarray(rng.normal(size=(1, 6, 6)) * 0.3, jnp.float32)
     x = jnp.asarray(rng.normal(size=(5, 2, 6)), jnp.float32)
@@ -266,22 +272,22 @@ def test_pipeline_single_stage_identity_schedule():
 
 
 def test_pipeline_rejects_wrong_mesh():
-    mesh = jax.make_mesh((1,), ("pp",))
+    mesh = _auto_mesh("pp")
     with pytest.raises(ValueError):
         build_pipeline_fn(lambda w, h: h, 4, 8, mesh, "pp")
 
 
 @pytest.mark.parametrize("mode", ["none", "int8", "topk"])
 def test_compressed_psum_single_device(mode):
-    from jax.experimental.shard_map import shard_map
-    mesh = jax.make_mesh((1,), ("pod",))
+    from jax import shard_map
+    mesh = _auto_mesh("pod")
     g = jnp.asarray(np.random.default_rng(4).normal(size=(32,)), jnp.float32)
 
     def body(gs):
         return compressed_psum({"g": gs}, "pod", mode=mode, k_frac=1.0)["g"]
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     with mesh:
         out = np.asarray(fn(g))
     atol = (float(jnp.max(jnp.abs(g))) / 127.0 * 0.51 + 1e-6

@@ -359,3 +359,20 @@ def test_fused_no_specs_passthrough():
                     jax.tree_util.tree_leaves(p2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert s1 == {}
+
+
+def test_backend_resolution_never_interprets_on_tpu(monkeypatch):
+    """One helper picks kernel vs jnp twin and interpret mode: on a TPU
+    backend the defaults are the compiled kernel; elsewhere the jnp twin /
+    the interpreter; an explicit argument always wins."""
+    from repro.kernels.backend import resolve_impl
+    assert resolve_impl("auto") == ("ref", True)
+    assert resolve_impl("pallas") == ("pallas", True)
+    assert resolve_impl("pallas", False) == ("pallas", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_impl("auto") == ("pallas", False)
+    assert resolve_impl("pallas") == ("pallas", False)
+    assert resolve_impl("ref") == ("ref", False)
+    assert resolve_impl("pallas", True) == ("pallas", True)
+    with pytest.raises(ValueError):
+        resolve_impl("triton")

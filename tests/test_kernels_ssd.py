@@ -80,3 +80,24 @@ def test_model_ssd_full_vs_decode_steps():
     y_steps = jnp.concatenate(ys, axis=1)
     np.testing.assert_allclose(np.asarray(y_full), np.asarray(y_steps),
                                atol=3e-4, rtol=3e-3)
+
+
+def test_model_ssd_gradients_finite_at_long_chunks():
+    """mamba2-370m's 64-token chunks: above the diagonal the decay exponent
+    cum_i - cum_j grows past exp's f32 range. Masking after the exp left an
+    inf whose zero cotangent made every SSM gradient NaN at the published
+    widths; masked before the exp, the gradients stay finite."""
+    from repro.models import ssm as SS
+    from repro.models.param import materialize
+
+    d, d_inner, n_state, headdim = 32, 64, 8, 8
+    params = materialize(jax.random.PRNGKey(1),
+                         SS.ssm_layout(d, d_inner, n_state, headdim),
+                         jnp.float32)
+    params["dt_bias"] = jnp.full_like(params["dt_bias"], 3.0)   # dt ~ 3
+    u = jnp.asarray(np.random.default_rng(1).normal(size=(2, 64, d)),
+                    jnp.float32)
+    grads = jax.grad(lambda p: jnp.sum(
+        SS.ssd_apply(p, u, headdim=headdim, chunk=64) ** 2))(params)
+    for name, g in grads.items():
+        assert np.all(np.isfinite(np.asarray(g))), name

@@ -599,7 +599,7 @@ def test_compressed_grad_reduce_composes_with_fused_sharded():
         import functools
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ProjectionSpec, ProjectionEngine
         from repro.dist.compression import compressed_psum
         from repro.optim.adam import AdamConfig, adam_init
@@ -633,7 +633,7 @@ def test_compressed_grad_reduce_composes_with_fused_sharded():
                 return shard_map(
                     body, mesh=mesh,
                     in_specs=(P("data", None, None),), out_specs=P(),
-                    check_rep=False)(gs)
+                    check_vma=False)(gs)
 
             def step(gs, o, p, s):
                 return eng.projected_update(gs, o, p, acfg, state=s,
@@ -775,6 +775,35 @@ def test_sharded_zoo_serve_matches_single_device():
                    "collective-permute"):
             assert not re.search(op, hlo), op
         assert "input_output_alias" in hlo   # donated cache + slot state
+        print("OK")
+    """)
+    assert "OK" in out
+
+
+def test_launch_train_lays_state_over_every_device():
+    """repro.launch.train builds its mesh from every visible device: the
+    params and optimizer state are laid out over all 8, and the losses
+    match the same run on one device."""
+    out = _run_subprocess("""
+        import numpy as np, jax
+        from repro.configs import get_reduced
+        from repro.data.pipeline import LMBatcher, SyntheticLM
+        from repro.launch import train as launch_train
+        from repro.models.zoo import build
+        from repro.train.loop import TrainConfig, train
+
+        argv = ["--arch", "gemma_7b", "--reduced", "--steps", "3",
+                "--batch", "8", "--seq", "16", "--resume", "none"]
+        out8 = launch_train.main(argv)
+        devs = {d for leaf in jax.tree_util.tree_leaves(out8["params"])
+                for d in leaf.sharding.device_set}
+        assert len(devs) == 8, devs
+        cfg = get_reduced("gemma_7b")
+        out1 = train(build(cfg), LMBatcher(SyntheticLM(cfg.vocab), 8, 16),
+                     TrainConfig(steps=3, lr=3e-4), resume=False)
+        print("losses", out8["losses"], out1["losses"])
+        np.testing.assert_allclose(out8["losses"], out1["losses"],
+                                   rtol=1e-5)
         print("OK")
     """)
     assert "OK" in out

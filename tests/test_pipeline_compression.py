@@ -50,7 +50,7 @@ def test_compressed_psum_int8():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.dist.compression import compressed_psum
 
         mesh = jax.make_mesh((4,), ("pod",),
@@ -62,7 +62,7 @@ def test_compressed_psum_int8():
             return compressed_psum({"g": gs[0]}, "pod", mode="int8")["g"]
 
         fn = shard_map(body, mesh=mesh, in_specs=(P("pod"),),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         with mesh:
             total_c = fn(g)
         total = np.asarray(g).sum(0)

@@ -157,3 +157,22 @@ def test_watchdog():
     time.sleep(0.05)  # straggler
     w.stop(5)
     assert events == [5]
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """The entry points' compile cache: $JAX_COMPILATION_CACHE_DIR when set
+    (nothing set in code), else a fixed directory in the checkout."""
+    import pathlib
+    from repro.launch.cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        root = pathlib.Path(__file__).resolve().parent.parent
+        assert path == str(root / ".jax_cache") == enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -119,9 +119,15 @@ def test_scatter_residual_matches_dense_gemm():
     w2[:, ::3] = 0.0
     sel = np.flatnonzero(np.any(w2 != 0, axis=0)).astype(np.int32)
     dense = h @ jnp.asarray(w2)
-    compact = scatter_residual(h @ jnp.asarray(w2[:, sel]),
-                               jnp.asarray(sel), 24)
-    np.testing.assert_array_equal(np.asarray(dense), np.asarray(compact))
+    compact = np.asarray(scatter_residual(h @ jnp.asarray(w2[:, sel]),
+                                          jnp.asarray(sel), 24))
+    dead = np.setdiff1d(np.arange(24), sel)
+    np.testing.assert_array_equal(compact[:, dead], 0.0)
+    np.testing.assert_array_equal(np.asarray(dense)[:, dead], 0.0)
+    # a 24-wide and a 16-wide GEMM may accumulate each dot product in a
+    # different order, so the surviving columns agree to rounding only
+    np.testing.assert_allclose(compact[:, sel], np.asarray(dense)[:, sel],
+                               rtol=1e-6)
 
 
 def test_unmatched_spec_leaf_is_skipped_dense():
