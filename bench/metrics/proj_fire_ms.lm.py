@@ -1,0 +1,14 @@
+"""One projected update on a step where the every_k gate fires (Adam and the packed Newton).
+
+Median device time of one execution of the program ``jit_bench_proj_fire_lm``, which the
+harness jits and calls on the window's own arrays, from the device trace."""
+
+MODULE = "jit_bench_proj_fire_lm"
+
+
+def read(ctx):
+    runs = (ctx.get("trace") or {}).get("modules", {}).get(MODULE)
+    if not runs:
+        return None
+    runs = sorted(runs)
+    return 1e3 * runs[len(runs) // 2]
