@@ -1,0 +1,17 @@
+"""The packed Newton solve of the projection inside the training step (the
+median step is one where the every_k gate is closed).
+
+Median over the executions of ``jit_lm_train_step``, the step the program's
+own loop runs, of the device time of its ops under the named scope
+``proj/newton`` (``bench/program_trace.py``'s ``scopes``, from the device
+trace)."""
+
+MODULE, SCOPE = "jit_lm_train_step", "proj/newton"
+
+
+def read(ctx):
+    runs = (ctx.get("trace") or {}).get("scopes", {}).get(MODULE)
+    if not runs:
+        return None
+    times = sorted(r.get(SCOPE, 0.0) for r in runs)
+    return 1e3 * times[len(times) // 2]

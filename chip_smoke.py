@@ -276,8 +276,8 @@ def lm_phase(on_tpu: bool) -> None:
     check_kernels(compiled.as_text(), "lm bilevel step", on_tpu)
     losses2 = []
     for s in range(LM_STEPS, LM_STEPS + LM_EXTRA_STEPS):
-        params, opt, proj, loss = compiled(params, opt, proj, batch(s),
-                                           lr_at(tcfg, s))
+        params, opt, proj, loss, _ = compiled(params, opt, proj, batch(s),
+                                              lr_at(tcfg, s))
         losses2.append(float(loss))
     log(f"  info: bilevel losses {losses2}")
     check(bool(np.all(np.isfinite(losses2))), "lm bilevel: losses finite")

@@ -31,7 +31,9 @@ Public API:
         train loop builds on; one packed solve per (family, every_k)
     apply_constraints_packed / init_projection_state  — functional shims
         over the engine (packed batching with warm-started Newton)
+    newton_evals             — Eq.-(19) evaluations of one projected update
     engine_counters / engine_counters_reset — solver-invocation accounting
+        (the registry of ``repro.obs``)
 """
 from .simplex import (project_simplex_sort, project_l1_ball,
                       project_weighted_l1_ball, simplex_threshold)
@@ -59,4 +61,4 @@ from .constraints import (ProjectionSpec, apply_constraints,
                           sparsity_report, engine_counters,
                           engine_counters_reset)
 from .engine import (ProjectionEngine, apply_constraints_packed,
-                     init_projection_state)
+                     init_projection_state, newton_evals)

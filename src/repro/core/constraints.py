@@ -21,10 +21,11 @@ threads through the train state as next step's Newton warm start (plan
 keys isolate warm starts per family — thetas never cross families).
 
 This module owns the STATIC side of that story — specs, leaf matching, plan
-building, pack/unpack, masks/reports, and the invocation counters. The
-runtime side (solver dispatch newton|pallas|sharded, theta state, the
-shared projected-update step core) lives in ``core.engine``; the
-mesh-resident distributed solve lives in ``dist.projection``.
+building, pack/unpack, masks/reports (the invocation counters live in
+``repro.obs`` and are re-exported here). The runtime side (solver
+dispatch newton|pallas|sharded, theta state, the shared projected-update
+step core) lives in ``core.engine``; the mesh-resident distributed solve
+lives in ``dist.projection``.
 
 This module is what makes the paper's technique a first-class framework
 feature: every arch config carries a tuple of specs (see configs/*.py).
@@ -39,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs import engine_count, engine_counters, engine_counters_reset
 from .families import family_for_norm, get_family, registered_norms
 from .norms import project_l1_ball
 
@@ -56,51 +58,6 @@ def _known_norms():
     return registered_norms() | _EXTRA_NORMS
 _LANE = 128   # TPU lane width: per-matrix column padding unit
 _SUBLANE = 8  # TPU sublane: packed-buffer row padding unit
-
-# Python-level projection-engine invocation counters, keyed by
-# "<plan key>/<solver>" for packed launches and "per_leaf" for the per-matrix
-# fallback. Incremented once per solver call issued while tracing/executing
-# eagerly — benchmarks and tests use them to demonstrate the
-# one-launch-per-step property of the packed path. Unlike the old
-# ENGINE_INVOCATIONS module dict, the registry is snapshot/reset-able so
-# concurrent benchmarks and tests cannot bleed counts into each other.
-_COUNTERS: Dict[str, int] = {}
-
-
-def engine_count(key: str) -> None:
-    """Increment one invocation counter (engine-internal).
-
-    ``key``: str — ``"<plan key>/<solver>"`` for packed launches (e.g.
-    ``"l1inf_packed/k1/newton"``) or ``"per_leaf"`` for the fallback path.
-    Counts Python-level solver calls (once per trace/eager call), so jit'd
-    steady state adds nothing — tests use that to prove one-launch-per-step.
-
-    >>> engine_count("l1inf_packed/k1/newton")
-    """
-    _COUNTERS[key] = _COUNTERS.get(key, 0) + 1
-
-
-def engine_counters() -> Dict[str, int]:
-    """Snapshot of all per-plan/per-path invocation counters.
-
-    Returns a plain ``{key: int}`` dict copy (mutating it does not touch
-    the live registry). Pair with ``engine_counters_reset`` around a
-    measured region to count solver launches attributable to that region.
-
-    >>> before = engine_counters()
-    """
-    return dict(_COUNTERS)
-
-
-def engine_counters_reset() -> None:
-    """Zero every counter (call before a measured region).
-
-    Global across all plans/solvers — benchmarks and tests reset, run one
-    region, then diff against ``engine_counters()``.
-
-    >>> engine_counters_reset()
-    """
-    _COUNTERS.clear()
 
 
 @dataclasses.dataclass(frozen=True)

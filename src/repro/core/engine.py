@@ -31,14 +31,14 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .constraints import (ProjectionSpec, build_packed_plans, engine_count,
-                          _apply_2d, _gated, _pack_entry, _project_fn,
-                          _unpack_entry)
+from ..obs import engine_count, scope
+from .constraints import (ProjectionSpec, build_packed_plans, _apply_2d,
+                          _gated, _pack_entry, _project_fn, _unpack_entry)
 from .families import get_family, project_segmented_family
 from .l1inf import _segmented_newton
 
 __all__ = ["ProjectionEngine", "apply_constraints_packed",
-           "init_projection_state"]
+           "init_projection_state", "newton_evals"]
 
 _SOLVERS = ("newton", "pallas", "sharded", "fused", "fused_sharded")
 
@@ -114,8 +114,9 @@ class ProjectionEngine:
         if eff == "sharded":
             from ..dist.projection import project_plan_sharded
             vals = [leaves[e.index] for e in plan.entries]
-            outs, theta, iters = project_plan_sharded(
-                vals, plan, self.mesh, theta0=theta0)
+            with scope("proj/newton"):
+                outs, theta, iters = project_plan_sharded(
+                    vals, plan, self.mesh, theta0=theta0)
             return dict(zip((e.index for e in plan.entries), outs)), \
                 theta, iters
         pieces = [_pack_entry(leaves[e.index], e, plan.n_max)
@@ -124,16 +125,17 @@ class ProjectionEngine:
         sids = jnp.asarray(plan.seg_ids())
         C_seg = jnp.asarray(plan.radii())
         w_col = jnp.asarray(plan.col_weights()) if fam.uses_weights else None
-        if self.solver == "pallas" and fam.pallas_loader is not None:
-            pallas_fn = fam.pallas_loader()
-            Xpk, theta = pallas_fn(
-                Ypk, sids, C_seg, num_segments=plan.num_segments,
-                theta0=theta0)
-            iters = jnp.asarray(-1, jnp.int32)   # kernel keeps its own count
-        else:
-            Xpk, theta, iters = project_segmented_family(
-                Ypk, sids, C_seg, num_segments=plan.num_segments,
-                family=plan.family, w_col=w_col, theta0=theta0)
+        with scope("proj/newton"):
+            if self.solver == "pallas" and fam.pallas_loader is not None:
+                pallas_fn = fam.pallas_loader()
+                Xpk, theta = pallas_fn(
+                    Ypk, sids, C_seg, num_segments=plan.num_segments,
+                    theta0=theta0)
+                iters = jnp.asarray(-1, jnp.int32)  # kernel keeps its count
+            else:
+                Xpk, theta, iters = project_segmented_family(
+                    Ypk, sids, C_seg, num_segments=plan.num_segments,
+                    family=plan.family, w_col=w_col, theta0=theta0)
         outs = {}
         for e in plan.entries:
             block = jax.lax.slice_in_dim(
@@ -236,34 +238,35 @@ class ProjectionEngine:
 
         Returns (params, opt_state, proj_state) (+ stats when requested).
         """
-        if grad_reduce is not None:
-            grads = grad_reduce(grads)
-        if self.solver in ("fused", "fused_sharded") and self.specs:
-            plans, per_leaf = self.plans(params)
-            fused_plans = [
-                p for p in plans
-                if p.every_k == 1
-                and hasattr(get_family(p.family).seg_ops, "from_colstats")]
-            if fused_plans:
-                return self._projected_update_fused(
-                    grads, opt_state, params, acfg, lr=lr, mask=mask,
-                    state=state, plans=plans, per_leaf=per_leaf,
-                    fused_plans=fused_plans, with_stats=with_stats)
-        from ..optim.adam import adam_update
-        new_params, new_opt = adam_update(grads, opt_state, params, acfg,
-                                          lr=lr, mask=mask)
-        stats: Dict[str, Any] = {}
-        if self.specs:
-            new_params, state, stats = self.apply(
-                new_params, step=new_opt.count, state=state, with_stats=True)
-            if mask is not None:
-                new_params = jax.tree_util.tree_map(
-                    lambda p, m: p * m, new_params, mask)
-        else:
-            state = dict(state or {})
-        if with_stats:
-            return new_params, new_opt, state, stats
-        return new_params, new_opt, state
+        with scope("proj/update"):
+            if grad_reduce is not None:
+                grads = grad_reduce(grads)
+            if self.solver in ("fused", "fused_sharded") and self.specs:
+                plans, per_leaf = self.plans(params)
+                fused_plans = [
+                    p for p in plans if p.every_k == 1 and hasattr(
+                        get_family(p.family).seg_ops, "from_colstats")]
+                if fused_plans:
+                    return self._projected_update_fused(
+                        grads, opt_state, params, acfg, lr=lr, mask=mask,
+                        state=state, plans=plans, per_leaf=per_leaf,
+                        fused_plans=fused_plans, with_stats=with_stats)
+            from ..optim.adam import adam_update
+            new_params, new_opt = adam_update(grads, opt_state, params, acfg,
+                                              lr=lr, mask=mask)
+            stats: Dict[str, Any] = {}
+            if self.specs:
+                new_params, state, stats = self.apply(
+                    new_params, step=new_opt.count, state=state,
+                    with_stats=True)
+                if mask is not None:
+                    new_params = jax.tree_util.tree_map(
+                        lambda p, m: p * m, new_params, mask)
+            else:
+                state = dict(state or {})
+            if with_stats:
+                return new_params, new_opt, state, stats
+            return new_params, new_opt, state
 
     def _projected_update_fused(self, grads, opt_state, params: Any, acfg,
                                 *, lr, mask, state, plans, per_leaf,
@@ -341,9 +344,10 @@ class ProjectionEngine:
             w_col = (jnp.asarray(plan.virtual_col_weights())
                      if fam.uses_weights else None)
             aux = fam.seg_ops.from_colstats(colsum, colmax, w_col)
-            mu, theta, iters, inside_seg, zero_seg = _segmented_newton(
-                aux, sids, C_seg, plan.num_segments, theta0, 32,
-                ops=fam.seg_ops)
+            with scope("proj/newton"):
+                mu, theta, iters, inside_seg, zero_seg = _segmented_newton(
+                    aux, sids, C_seg, plan.num_segments, theta0, 32,
+                    ops=fam.seg_ops)
             # fold the identity/zero segment gating into the per-column
             # level so pass 2 is a single min()/multiply — no virtual
             # columns are padding, so the lookups need no sentinel
@@ -412,6 +416,20 @@ class ProjectionEngine:
         if with_stats:
             return new_params, new_opt, new_state, stats
         return new_params, new_opt, new_state
+
+
+def newton_evals(stats: Dict[str, Any]) -> jnp.ndarray:
+    """Eq.-(19) evaluations of one projected update: the counts of a
+    ``with_stats`` dict summed over its plans, as an int32 scalar. The
+    Pallas solver keeps its own count and reports -1; it adds nothing.
+
+    >>> p, o, s, stats = engine.projected_update(..., with_stats=True)
+    >>> n = newton_evals(stats)
+    """
+    total = jnp.zeros((), jnp.int32)
+    for iters in stats.values():
+        total = total + jnp.maximum(jnp.asarray(iters, jnp.int32), 0)
+    return total
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from .param import PM
 from .layers import rmsnorm_apply
 from ..dist.sharding import shard
+from ..obs import scope
 
 CONV_W = 4  # causal depthwise conv width
 
@@ -78,64 +79,66 @@ def ssd_apply(params, u: jnp.ndarray, *, headdim: int, chunk: int = 64,
     Cm = _causal_conv(Cm, params["conv_C"])
     x = shard(x, "batch", "seq", "mlp")
 
-    H = params["A_log"].shape[0]
-    P = headdim
-    N = Bm.shape[-1]
-    xh = x.reshape(B_, S, H, P).astype(jnp.float32)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + params["dt_bias"].astype(jnp.float32))  # (B,S,H)
-    a = -jnp.exp(params["A_log"].astype(jnp.float32))              # (H,) < 0
-    da = dt * a[None, None, :]                                     # (B,S,H)
+    with scope("ssd/chunk_scan"):
+        H = params["A_log"].shape[0]
+        P = headdim
+        N = Bm.shape[-1]
+        xh = x.reshape(B_, S, H, P).astype(jnp.float32)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + params["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(params["A_log"].astype(jnp.float32))      # (H,) < 0
+        da = dt * a[None, None, :]                             # (B,S,H)
 
-    nc = S // chunk
-    assert S % chunk == 0, (S, chunk)
-    Q = chunk
-    da_c = da.reshape(B_, nc, Q, H)
-    dt_c = dt.reshape(B_, nc, Q, H)
-    x_c = xh.reshape(B_, nc, Q, H, P)
-    B_c = Bm.reshape(B_, nc, Q, N).astype(jnp.float32)
-    C_c = Cm.reshape(B_, nc, Q, N).astype(jnp.float32)
+        nc = S // chunk
+        assert S % chunk == 0, (S, chunk)
+        Q = chunk
+        da_c = da.reshape(B_, nc, Q, H)
+        dt_c = dt.reshape(B_, nc, Q, H)
+        x_c = xh.reshape(B_, nc, Q, H, P)
+        B_c = Bm.reshape(B_, nc, Q, N).astype(jnp.float32)
+        C_c = Cm.reshape(B_, nc, Q, N).astype(jnp.float32)
 
-    cum = jnp.cumsum(da_c, axis=2)                                 # (B,nc,Q,H)
-    seg_total = cum[:, :, -1]                                      # (B,nc,H)
+        cum = jnp.cumsum(da_c, axis=2)                         # (B,nc,Q,H)
+        seg_total = cum[:, :, -1]                              # (B,nc,H)
 
-    # ---- intra-chunk (quadratic within chunk) ------------------------------
-    # L[b,c,h,i,j] = exp(cum_i - cum_j) for i >= j else 0. Mask BEFORE the
-    # exp: above the diagonal cum_i - cum_j > 0 grows with the chunk and
-    # overflows exp to inf, and the masked inf turns into NaN gradients
-    tdt = jnp.bfloat16 if tile_bf16 else jnp.float32
-    diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]           # (B,nc,Q,Q,H)
-    tri = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.exp(jnp.where(tri[None, None, :, :, None], diff,
-                          -jnp.inf)).astype(tdt)
-    G = jnp.einsum("bcin,bcjn->bcij", C_c.astype(tdt),
-                   B_c.astype(tdt))                                # (B,nc,Q,Q)
-    M = G[..., None] * L                                           # (B,nc,Q,Q,H)
-    intra = jnp.einsum("bcijh,bcjh,bcjhp->bcihp", M, dt_c.astype(tdt),
-                       x_c.astype(tdt)).astype(jnp.float32)
+        # ---- intra-chunk (quadratic within chunk) --------------------------
+        # L[b,c,h,i,j] = exp(cum_i - cum_j) for i >= j else 0. Mask BEFORE
+        # the exp: above the diagonal cum_i - cum_j > 0 grows with the chunk
+        # and overflows exp to inf, and the masked inf turns into NaN
+        # gradients
+        tdt = jnp.bfloat16 if tile_bf16 else jnp.float32
+        diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (B,nc,Q,Q,H)
+        tri = jnp.tril(jnp.ones((Q, Q), bool))
+        L = jnp.exp(jnp.where(tri[None, None, :, :, None], diff,
+                              -jnp.inf)).astype(tdt)
+        G = jnp.einsum("bcin,bcjn->bcij", C_c.astype(tdt),
+                       B_c.astype(tdt))                        # (B,nc,Q,Q)
+        M = G[..., None] * L                                   # (B,nc,Q,Q,H)
+        intra = jnp.einsum("bcijh,bcjh,bcjhp->bcihp", M, dt_c.astype(tdt),
+                           x_c.astype(tdt)).astype(jnp.float32)
 
-    # ---- chunk states + inter-chunk recurrence -----------------------------
-    decay_to_end = jnp.exp(seg_total[:, :, None, :] - cum)         # (B,nc,Q,H)
-    states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn",
-                        B_c, dt_c * decay_to_end, x_c)
+        # ---- chunk states + inter-chunk recurrence -------------------------
+        decay_to_end = jnp.exp(seg_total[:, :, None, :] - cum)  # (B,nc,Q,H)
+        states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn",
+                            B_c, dt_c * decay_to_end, x_c)
 
-    def scan_chunks(h_prev, inp):
-        st, seg = inp                                              # (B,H,P,N), (B,H)
-        h_new = h_prev * jnp.exp(seg)[:, :, None, None] + st
-        return h_new, h_prev
+        def scan_chunks(h_prev, inp):
+            st, seg = inp                                 # (B,H,P,N), (B,H)
+            h_new = h_prev * jnp.exp(seg)[:, :, None, None] + st
+            return h_new, h_prev
 
-    h0 = jnp.zeros((B_, H, P, N), jnp.float32)
-    _, h_before = jax.lax.scan(
-        scan_chunks, h0,
-        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(seg_total, 1, 0)))
-    h_before = jnp.moveaxis(h_before, 0, 1)                        # (B,nc,H,P,N)
+        h0 = jnp.zeros((B_, H, P, N), jnp.float32)
+        _, h_before = jax.lax.scan(
+            scan_chunks, h0,
+            (jnp.moveaxis(states, 1, 0), jnp.moveaxis(seg_total, 1, 0)))
+        h_before = jnp.moveaxis(h_before, 0, 1)                # (B,nc,H,P,N)
 
-    inter = jnp.einsum("bcin,bcih,bchpn->bcihp",
-                       C_c, jnp.exp(cum), h_before)
+        inter = jnp.einsum("bcin,bcih,bchpn->bcihp",
+                           C_c, jnp.exp(cum), h_before)
 
-    y = (intra + inter).reshape(B_, S, H, P)
-    y = y + params["D"].astype(jnp.float32)[None, None, :, None] * xh
-    y = y.reshape(B_, S, H * P).astype(u.dtype)
+        y = (intra + inter).reshape(B_, S, H, P)
+        y = y + params["D"].astype(jnp.float32)[None, None, :, None] * xh
+        y = y.reshape(B_, S, H * P).astype(u.dtype)
 
     # gated output norm (mamba2: RMSNorm(y * silu(z)))
     y = rmsnorm_apply({"scale": params["norm"]}, y * jax.nn.silu(z))

@@ -17,8 +17,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core import (ProjectionEngine, ProjectionSpec, column_masks,
-                    family_for_norm, sparsity_report)
+                    family_for_norm, newton_evals, sparsity_report)
 from ..optim import AdamConfig, adam_init
 from .model import SAEConfig, sae_init, sae_loss, accuracy
 
@@ -58,14 +59,20 @@ def _make_step(cfg: SAEConfig, tcfg: SAETrainConfig, acfg: AdamConfig):
     engine = ProjectionEngine(specs, solver="fused")
 
     @jax.jit
-    def step(params, opt_state, proj_state, x, y, mask):
-        (loss, aux), grads = jax.value_and_grad(
-            lambda p: sae_loss(p, x, y, cfg), has_aux=True)(params)
-        params, opt_state, proj_state = engine.projected_update(
-            grads, opt_state, params, acfg, mask=mask, state=proj_state)
-        return params, opt_state, proj_state, loss, aux
+    def sae_step(params, opt_state, proj_state, x, y, mask):
+        """One projected step; the last output is the update's Eq.-(19)
+        evaluation count (``core.newton_evals``)."""
+        obs.count("sae/step_traces")        # the body runs once per trace
+        with obs.scope("fwd_bwd"):
+            (loss, aux), grads = jax.value_and_grad(
+                lambda p: sae_loss(p, x, y, cfg), has_aux=True)(params)
+        params, opt_state, proj_state, stats = engine.projected_update(
+            grads, opt_state, params, acfg, mask=mask, state=proj_state,
+            with_stats=True)
+        return (params, opt_state, proj_state, loss, aux,
+                newton_evals(stats))
 
-    return step, engine
+    return sae_step, engine
 
 
 def _compaction_ratio(params, specs) -> float:
@@ -83,20 +90,40 @@ def _run_descent(params, step_fn, engine, X, y, tcfg, mask, rng, specs=()):
     proj_state = engine.init_state(params)
     n = X.shape[0]
     history, compaction = [], []
+    k = 0
     for epoch in range(tcfg.epochs):
         perm = rng.permutation(n)
+        evals = []
         for s in range(0, n, tcfg.batch_size):
-            idx = perm[s:s + tcfg.batch_size]
-            params, opt_state, proj_state, loss, aux = step_fn(
-                params, opt_state, proj_state, X[idx], y[idx], mask)
-        history.append(float(loss))
-        compaction.append(_compaction_ratio(params, specs))
+            with obs.span("sae/batch"):
+                idx = perm[s:s + tcfg.batch_size]
+                xb, yb = X[idx], y[idx]
+            with obs.span("sae/step", step=k):
+                params, opt_state, proj_state, loss, aux, ev = step_fn(
+                    params, opt_state, proj_state, xb, yb, mask)
+            evals.append(ev)
+            k += 1
+        with obs.span("sae/epoch_end"):
+            # the epoch's one sync: its last loss and every step's count
+            loss, evals = jax.device_get((loss, evals))
+            history.append(float(loss))
+            compaction.append(_compaction_ratio(params, specs))
+        if engine.specs:
+            obs.count("proj/updates", len(evals))
+            obs.count("proj/newton_evals", int(np.sum(evals)))
     return params, history, compaction
 
 
 def train_sae(X_train: np.ndarray, y_train: np.ndarray,
               X_test: np.ndarray, y_test: np.ndarray,
               cfg: SAEConfig, tcfg: SAETrainConfig) -> SAEResult:
+    """One fit of Algorithm 3, under the host span ``sae/fit``."""
+    obs.count("sae/fits")
+    with obs.span("sae/fit"):
+        return _fit(X_train, y_train, X_test, y_test, cfg, tcfg)
+
+
+def _fit(X_train, y_train, X_test, y_test, cfg, tcfg) -> SAEResult:
     key = jax.random.PRNGKey(tcfg.seed)
     rng = np.random.default_rng(tcfg.seed)
     X_train = jnp.asarray(X_train)
@@ -134,25 +161,30 @@ def train_sae(X_train: np.ndarray, y_train: np.ndarray,
 
     # ---- double descent: mask, rewind, retrain -------------------------
     if tcfg.projection and tcfg.double_descent:
-        specs = (tcfg1.projection,)
-        masks = column_masks(params, specs)
-        rewound = jax.tree_util.tree_map(lambda p0, m: p0 * m, params0, masks)
-        if masked_mode:  # retrain mask-only, no clipping
-            import dataclasses as _dc
-            step_fn, step_engine = _make_step(
-                cfg, _dc.replace(tcfg, projection=None), acfg)
+        with obs.span("sae/rewind"):
+            specs = (tcfg1.projection,)
+            masks = column_masks(params, specs)
+            rewound = jax.tree_util.tree_map(lambda p0, m: p0 * m, params0,
+                                             masks)
+            if masked_mode:  # retrain mask-only, no clipping
+                import dataclasses as _dc
+                step_fn, step_engine = _make_step(
+                    cfg, _dc.replace(tcfg, projection=None), acfg)
         params, hist2, comp2 = _run_descent(rewound, step_fn, step_engine,
                                             X_train, y_train_j, tcfg, masks,
                                             rng, specs=eval_specs)
         history.append(("descent2", hist2))
         compaction_history.append(("descent2", comp2))
 
-    test_acc = float(accuracy(params, jnp.asarray(X_test), jnp.asarray(y_test)))
-    w1 = np.asarray(params["enc1"]["w"])
-    live = np.any(w1 != 0, axis=1)
-    colsp = 100.0 * (1.0 - live.mean())
-    return SAEResult(params=params, test_accuracy=test_acc,
-                     column_sparsity=float(colsp),
-                     selected=np.nonzero(live)[0], history=history,
-                     compaction_history=compaction_history,
-                     compaction_ratio=_compaction_ratio(params, eval_specs))
+    with obs.span("sae/eval"):
+        test_acc = float(accuracy(params, jnp.asarray(X_test),
+                                  jnp.asarray(y_test)))
+        w1 = np.asarray(params["enc1"]["w"])
+        live = np.any(w1 != 0, axis=1)
+        colsp = 100.0 * (1.0 - live.mean())
+        return SAEResult(params=params, test_accuracy=test_acc,
+                         column_sparsity=float(colsp),
+                         selected=np.nonzero(live)[0], history=history,
+                         compaction_history=compaction_history,
+                         compaction_ratio=_compaction_ratio(params,
+                                                            eval_specs))

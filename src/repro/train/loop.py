@@ -17,9 +17,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import obs
 from ..models.zoo import Model
 from ..optim import AdamConfig, adam_init
-from ..core import ProjectionEngine, sparsity_report
+from ..core import ProjectionEngine, newton_evals, sparsity_report
 from ..checkpoint import AsyncCheckpointer, latest_step, restore_tree
 from ..dist.sharding import axis_rules
 from ..dist.watchdog import StepWatchdog
@@ -47,41 +48,45 @@ def build_accum_step(model: Model, acfg: AdamConfig, tcfg: TrainConfig,
     """jit'd train step with optional microbatch accumulation via lax.scan.
     The update half is the shared ``ProjectionEngine.projected_update`` step
     core (Adam + packed warm-started projection + every_k gate); the
-    default engine is ``launch.steps.projection_engine_for`` this mesh."""
+    default engine is ``launch.steps.projection_engine_for`` this mesh.
+    The step returns (params, opt_state, proj_state, loss, the update's
+    Eq.-(19) evaluation count)."""
     if engine is None:
         engine = projection_engine_for(model.cfg, mesh, tcfg.with_projection)
 
     def loss_fn(params, batch):
         return model.loss(params, batch)
 
-    def step(params, opt_state, proj_state, batch, lr):
+    def lm_train_step(params, opt_state, proj_state, batch, lr):
         with axis_rules(mesh, rules):
-            if tcfg.microbatches > 1:
-                def micro(carry, mb):
-                    (g_acc, l_acc) = carry
-                    (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                        params, mb)
-                    g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
-                    return (g_acc, l_acc + l), None
+            with obs.scope("fwd_bwd"):
+                if tcfg.microbatches > 1:
+                    def micro(carry, mb):
+                        (g_acc, l_acc) = carry
+                        (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                            params, mb)
+                        g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
+                        return (g_acc, l_acc + l), None
 
-                mbs = jax.tree_util.tree_map(
-                    lambda x: x.reshape((tcfg.microbatches,
-                                         x.shape[0] // tcfg.microbatches)
-                                        + x.shape[1:]), batch)
-                g0 = jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
-                (grads, loss), _ = jax.lax.scan(micro, (g0, 0.0), mbs)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g / tcfg.microbatches, grads)
-                loss = loss / tcfg.microbatches
-            else:
-                (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, batch)
-            params, opt_state, proj_state = engine.projected_update(
-                grads, opt_state, params, acfg, lr=lr, state=proj_state)
-        return params, opt_state, proj_state, loss
+                    mbs = jax.tree_util.tree_map(
+                        lambda x: x.reshape((tcfg.microbatches,
+                                             x.shape[0] // tcfg.microbatches)
+                                            + x.shape[1:]), batch)
+                    g0 = jax.tree_util.tree_map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                    (grads, loss), _ = jax.lax.scan(micro, (g0, 0.0), mbs)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g / tcfg.microbatches, grads)
+                    loss = loss / tcfg.microbatches
+                else:
+                    (loss, _), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True)(params, batch)
+            params, opt_state, proj_state, stats = engine.projected_update(
+                grads, opt_state, params, acfg, lr=lr, state=proj_state,
+                with_stats=True)
+        return params, opt_state, proj_state, loss, newton_evals(stats)
 
-    return jax.jit(step, donate_argnums=(0, 1, 2))
+    return jax.jit(lm_train_step, donate_argnums=(0, 1, 2))
 
 
 def lr_at(tcfg: TrainConfig, step: int) -> float:
@@ -143,13 +148,23 @@ def train(model: Model, batcher: LMBatcher, tcfg: TrainConfig,
     losses = []
     step_metrics = []   # per-step watchdog snapshots (dist/watchdog.py)
     for step in range(start_step, tcfg.steps):
-        batch = place_batch(
-            jax.tree_util.tree_map(jnp.asarray, batcher.get(step)))
+        with obs.span("train/batch"):
+            batch = place_batch(
+                jax.tree_util.tree_map(jnp.asarray, batcher.get(step)))
         watchdog.start()
-        params, opt_state, proj_state, loss = step_fn(
-            params, opt_state, proj_state, batch, lr_at(tcfg, step))
-        loss_f = float(loss)
+        with obs.span("train/step", step=step):
+            out = step_fn(params, opt_state, proj_state, batch,
+                          lr_at(tcfg, step))
+        # a step substituted for build_accum_step's (a test double) may
+        # return only the first four outputs
+        params, opt_state, proj_state, loss = out[:4]
+        with obs.span("train/sync"):
+            loss_f, evals = jax.device_get((loss, out[4:]))
+        loss_f = float(loss_f)
         dt = watchdog.stop(step)
+        if evals and engine.specs:
+            obs.count("proj/updates")
+            obs.count("proj/newton_evals", int(evals[0]))
         step_metrics.append(watchdog.metrics())
         losses.append(loss_f)
         if on_step:
