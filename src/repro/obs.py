@@ -13,12 +13,14 @@
 There is no switch. Without a running profiler a span costs a flag check
 and a scope exists only in the compiled program's metadata.
 
-Spans (host loops): ``sae/fit``, ``sae/batch``, ``sae/step``,
+Spans (host loops): ``sae/fit``, ``sae/batch`` (once per epoch, around the
+one program that makes the epoch's batches), ``sae/step``,
 ``sae/epoch_end``, ``sae/rewind``, ``sae/eval`` (``sae/train.py``);
 ``train/batch``, ``train/step``, ``train/sync`` (``train/loop.py``).
 Scopes (jitted steps): ``fwd_bwd``, ``proj/update``, ``proj/newton``,
 ``ssd/chunk_scan``. Counters: ``sae/fits``, ``sae/step_traces`` (once per
-trace of the SAE step), ``proj/updates`` and ``proj/newton_evals`` (read at
+trace of the SAE step), ``sae/batch_programs`` (once per epoch's batch
+program dispatched), ``proj/updates`` and ``proj/newton_evals`` (read at
 the loops' syncs), and the projection engine's routing counts, keyed
 ``"<plan key>/<solver>"`` or ``"per_leaf"``, incremented once per solver
 call traced or run eagerly.

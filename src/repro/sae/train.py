@@ -84,6 +84,16 @@ def _compaction_ratio(params, specs) -> float:
     return float(np.mean([1.0 - v / 100.0 for v in rep.values()]))
 
 
+@functools.partial(jax.jit, static_argnames="batch_size")
+def _epoch_batches(X, y, perm, batch_size):
+    """An epoch's batches ``(X[perm[s:s + b]], y[perm[s:s + b]])`` in order,
+    the last one ragged, made on the device by one program. The data comes
+    in as arguments, never closed over, so the program holds no data and
+    traces once per process for each shape of ``X``, ``y`` and batch size."""
+    return tuple((X[perm[s:s + batch_size]], y[perm[s:s + batch_size]])
+                 for s in range(0, perm.shape[0], batch_size))
+
+
 def _run_descent(params, step_fn, engine, X, y, tcfg, mask, rng, specs=()):
     acfg = AdamConfig(lr=tcfg.lr)
     opt_state = adam_init(params, acfg)
@@ -93,11 +103,11 @@ def _run_descent(params, step_fn, engine, X, y, tcfg, mask, rng, specs=()):
     k = 0
     for epoch in range(tcfg.epochs):
         perm = rng.permutation(n)
+        with obs.span("sae/batch"):
+            batches = _epoch_batches(X, y, perm, batch_size=tcfg.batch_size)
+        obs.count("sae/batch_programs")
         evals = []
-        for s in range(0, n, tcfg.batch_size):
-            with obs.span("sae/batch"):
-                idx = perm[s:s + tcfg.batch_size]
-                xb, yb = X[idx], y[idx]
+        for xb, yb in batches:
             with obs.span("sae/step", step=k):
                 params, opt_state, proj_state, loss, aux, ev = step_fn(
                     params, opt_state, proj_state, xb, yb, mask)

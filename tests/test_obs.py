@@ -13,7 +13,7 @@ from repro.core import ProjectionSpec
 from repro.optim import AdamConfig, adam_init
 from repro.sae import SAEConfig, SAETrainConfig, train_sae
 from repro.sae.model import sae_init
-from repro.sae.train import _make_step
+from repro.sae.train import _epoch_batches, _make_step
 
 SPEC = ProjectionSpec(pattern="enc1/w", norm="l1inf", radius=0.5, axis=1)
 TRAIN_KEYS = {"params", "opt_state", "losses", "proj_state", "sparsity",
@@ -138,6 +138,22 @@ def test_unprojected_sae_counts_no_updates():
     assert "proj/updates" not in c and "proj/newton_evals" not in c
 
 
+@pytest.mark.parametrize("spec,descents", [(SPEC, 2), (None, 1)])
+def test_each_epochs_batches_come_from_one_program_traced_once(spec,
+                                                               descents):
+    X, y = _sae_data(100)
+    cfg = SAEConfig(n_features=40, n_hidden=8, n_classes=2)
+    tcfg = SAETrainConfig(epochs=3, batch_size=32, projection=spec)
+    train_sae(X, y, X, y, cfg, tcfg)
+    assert obs.counters()["sae/batch_programs"] == 3 * descents
+    traced = _epoch_batches._cache_size()
+    assert traced >= 1
+    # the same shapes, other permutations
+    train_sae(X, y, X, y, cfg, dataclasses.replace(tcfg, seed=1))
+    assert obs.counters()["sae/batch_programs"] == 2 * 3 * descents
+    assert _epoch_batches._cache_size() == traced   # no new trace
+
+
 def _profiled(fn, tmp_path):
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -165,7 +181,7 @@ def test_a_profiled_fit_holds_one_step_span_per_step(tmp_path):
     assert len(steps) == 2 * per_descent
     assert sorted(s["step_num"] for s in steps) == \
         sorted(list(range(per_descent)) * 2)
-    assert len(spans["repro/sae/batch"]) == 2 * per_descent
+    assert len(spans["repro/sae/batch"]) == 2 * 2     # one per epoch
     assert len(spans["repro/sae/epoch_end"]) == 2 * 2
     for name in ("repro/sae/fit", "repro/sae/rewind", "repro/sae/eval"):
         assert len(spans[name]) == 1, name

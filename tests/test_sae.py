@@ -5,7 +5,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import ProjectionSpec
+import repro.sae.train as sae_train
+from repro.core import ProjectionSpec, column_masks
+from repro.optim import AdamConfig, adam_init
 from repro.sae import (SAEConfig, SAETrainConfig, sae_init, sae_apply,
                        sae_loss, make_classification, make_lung_surrogate,
                        train_test_split, train_sae)
@@ -80,3 +82,77 @@ def test_baseline_no_projection_runs():
                     SAETrainConfig(epochs=25, lr=2e-3, projection=None, seed=0))
     assert res.column_sparsity == 0.0
     assert res.test_accuracy > 0.6
+
+
+def _eager_fit_losses(X, y, cfg, tcfg):
+    """Every step's loss of Algorithm 3 with the batches gathered eagerly,
+    ``X[perm[s:s + b]]`` at each step: the loop the fit's one-program-per-
+    epoch batches replaced, kept here as the reference."""
+    acfg = AdamConfig(lr=tcfg.lr)
+    step, engine = sae_train._make_step(cfg, tcfg, acfg)
+    rng = np.random.default_rng(tcfg.seed)
+    params0 = sae_init(jax.random.PRNGKey(tcfg.seed), cfg)
+    Xj, yj = jnp.asarray(X), jnp.asarray(y)
+
+    def descent(params, mask):
+        opt_state, proj_state = adam_init(params, acfg), engine.init_state(
+            params)
+        losses = []
+        for _ in range(tcfg.epochs):
+            perm = rng.permutation(len(X))
+            for s in range(0, len(X), tcfg.batch_size):
+                idx = perm[s:s + tcfg.batch_size]
+                params, opt_state, proj_state, loss, *_ = step(
+                    params, opt_state, proj_state, Xj[idx], yj[idx], mask)
+                losses.append(float(loss))
+        return params, losses
+
+    params, losses1 = descent(
+        params0, jax.tree_util.tree_map(jnp.ones_like, params0))
+    masks = column_masks(params, (tcfg.projection,))
+    _, losses2 = descent(
+        jax.tree_util.tree_map(lambda p, m: p * m, params0, masks), masks)
+    return losses1 + losses2
+
+
+def test_fit_batches_are_the_eager_gather_of_each_permutation(monkeypatch):
+    """n = 100 in batches of 32: 32, 32, 32 and a ragged 4, two epochs, both
+    descents. The step is wrapped through ``_make_step`` as the benchmark's
+    check wraps it, and must see exactly the rows the permutations of
+    ``default_rng(seed)`` name, and give the losses of the eager loop."""
+    rng = np.random.default_rng(7)
+    X = rng.standard_normal((100, 24)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.int32)
+    cfg = SAEConfig(n_features=24, n_hidden=8, n_classes=2)
+    tcfg = SAETrainConfig(epochs=2, batch_size=32, seed=5, projection=
+                          ProjectionSpec(pattern="enc1/w", norm="l1inf",
+                                         radius=0.5, axis=1))
+    seen = []
+    make_step = sae_train._make_step
+
+    def recording_make_step(*args):
+        step, engine = make_step(*args)
+
+        def step_and_keep(*inputs):
+            out = step(*inputs)
+            seen.append((inputs[3], inputs[4], float(out[3])))
+            return out
+        return step_and_keep, engine
+
+    monkeypatch.setattr(sae_train, "_make_step", recording_make_step)
+    res = train_sae(X, y, X, y, cfg, tcfg)
+    monkeypatch.undo()
+
+    perms = np.random.default_rng(tcfg.seed)
+    want = [perm[s:s + 32] for perm in (perms.permutation(100)
+                                        for _ in range(2 * 2))
+            for s in range(0, 100, 32)]
+    assert len(seen) == len(want) == 2 * 2 * 4
+    for (xb, yb, _), idx in zip(seen, want):
+        assert xb.shape == (len(idx), 24) and yb.shape == (len(idx),)
+        np.testing.assert_array_equal(np.asarray(xb), X[idx])
+        np.testing.assert_array_equal(np.asarray(yb), y[idx])
+
+    losses = [loss for *_, loss in seen]
+    assert losses == _eager_fit_losses(X, y, cfg, tcfg)
+    assert [v for _, h in res.history for v in h] == losses[3::4]
